@@ -73,8 +73,8 @@ def lstm_bench(fast: bool) -> None:
     b, d, h = 128, 512, 512
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     xh = jax.random.normal(ks[0], (b, d + h))
-    w = jax.random.normal(ks[1], (d + h, h, 4)) * 0.1
-    bias = jax.random.normal(ks[2], (h, 4)) * 0.1
+    w = jax.random.normal(ks[1], (4, d + h, h)) * 0.1
+    bias = jax.random.normal(ks[2], (4, h)) * 0.1
     c = jax.random.normal(ks[3], (b, h))
     fn = jax.jit(lstm_cell_ref)
     us = timeit(fn, xh, w, bias, c)

@@ -2,7 +2,8 @@
 
 ``python -m benchmarks.run [--fast]`` prints ``name,us_per_call,derived``
 CSV. ``--fast`` uses reduced epochs (CI-sized); the full runs are what
-EXPERIMENTS.md cites.
+EXPERIMENTS.md cites. A bench that raises prints a ``BENCH_ERROR_`` row,
+the rest still run, and the exit status is non-zero.
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ import traceback
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="reduced epoch sizes (CI)")
@@ -29,6 +30,7 @@ def main() -> None:
     groups = (paper_figures.ALL + kernels_bench.ALL + padding_waste.ALL
               + dryrun_summary.ALL)
     only = args.only.split(",") if args.only else None
+    failed = []
     for fn in groups:
         if only and not any(o in fn.__name__ for o in only):
             continue
@@ -38,7 +40,11 @@ def main() -> None:
             print(f"BENCH_ERROR_{fn.__name__},0,{type(e).__name__}: {e}",
                   flush=True)
             traceback.print_exc(file=sys.stderr)
+            failed.append(fn.__name__)
+    if failed:
+        print(f"benchmarks failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

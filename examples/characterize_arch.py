@@ -14,8 +14,10 @@ restated for the XLA era (DESIGN.md §2).
 """
 import os
 
+# CPU-only compile tool: virtual host devices, never the accelerator
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import sys

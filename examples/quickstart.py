@@ -42,6 +42,7 @@ from repro.core import select_seqpoints, frequent, median, worst, prior
 from repro.core.characterize import WallclockProvider, epoch_log_from_plan
 from repro.core.reproduction import SETUPS
 from repro.data.batching import plan_epoch
+from repro.launch.compile_cache import enable_compile_cache
 
 # fires data-loader, NaN-loss, straggler, preemption, and silent-checkpoint
 # -corruption faults inside a 14-step run checkpointed every 4 steps
@@ -300,6 +301,7 @@ def main() -> None:
                     help="run only the serving-load drill: SL-aware "
                          "continuous batching vs run-to-completion")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.obs_dir:
         obs.enable(out_dir=args.obs_dir)
 

@@ -24,6 +24,7 @@ from repro.configs import (
 )
 from repro.data.batching import DataIterator
 from repro.data.synthetic import lm_documents
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Runtime, build_model
 from repro.train.trainer import Trainer
 
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(
         name="lm-110m", family="dense", num_layers=args.layers,

@@ -27,6 +27,7 @@ from repro import obs
 from repro.core.profile import EpochLog, SLTable
 from repro.core.seqpoint import SeqPointSet, select_seqpoints
 from repro.data.batching import BatchPlan
+from repro.perfmodel.hlo import parse_collectives
 from repro.perfmodel.machine import MachineConfig
 
 
@@ -98,11 +99,7 @@ class CompiledCostProvider:
                 ca = compiled.cost_analysis()
             flops = float(ca.get("flops", 0.0))
             bts = float(ca.get("bytes accessed", 0.0))
-            try:
-                from repro.perfmodel.hlo import parse_collectives
-                coll = parse_collectives(compiled.as_text()).wire_bytes
-            except Exception:
-                coll = 0.0
+            coll = parse_collectives(compiled.as_text()).wire_bytes
             self.cost_cache[sl] = (flops, bts, coll)
             self.profile_costs[sl] = time.perf_counter() - t0
             obs.metrics.histogram("profile_cost_s", provider="compiled",

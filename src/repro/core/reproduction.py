@@ -12,7 +12,10 @@ Two tracks (DESIGN.md §2/§3):
 Also measured: per-SL profiling cost (XLA compile+measure seconds) — the
 quantity SeqPoint reduces by ~two orders of magnitude (paper §VI-F).
 
-Results cache to results/repro_<network>.json; benchmarks/ are thin readers.
+Results cache to results/repro_<network><tag>_<device>.json, keyed by the
+platform and device kind that produced them, so a result is only ever
+returned on the kind of device it was measured on; benchmarks/ are thin
+readers.
 """
 from __future__ import annotations
 
@@ -120,12 +123,27 @@ def _hlo_op_histogram(lowered) -> Dict[str, int]:
     return dict(Counter(f"{op}:{shape}" for shape, op in ops))
 
 
+def _device() -> dict:
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def cache_path(network: str, tag: str = "") -> str:
+    """Where this device's reproduction result for ``network`` is cached."""
+    dev = _device()
+    key = re.sub(r"[^a-z0-9]+", "-",
+                 f"{dev['platform']}-{dev['kind']}".lower()).strip("-")
+    return os.path.join(RESULTS_DIR, f"repro_{network}{tag}_{key}.json")
+
+
 def run_reproduction(network: str, *, error_threshold: float = 0.02,
                      seed: int = 0, force: bool = False,
                      samples: Optional[int] = None,
                      tag: str = "") -> dict:
     os.makedirs(RESULTS_DIR, exist_ok=True)
-    out_path = os.path.join(RESULTS_DIR, f"repro_{network}{tag}.json")
+    out_path = cache_path(network, tag)
     if os.path.exists(out_path) and not force:
         with open(out_path) as f:
             return json.load(f)
@@ -142,6 +160,7 @@ def run_reproduction(network: str, *, error_threshold: float = 0.02,
     uniq = sorted(set(int(s) for s in plan.padded_sls))
     result: dict = {
         "network": network,
+        "device": _device(),
         "num_iterations": plan.num_batches,
         "num_unique_sls": len(uniq),
         "unique_sls": uniq,

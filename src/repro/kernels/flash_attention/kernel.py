@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -104,7 +100,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

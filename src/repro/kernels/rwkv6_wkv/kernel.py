@@ -10,7 +10,11 @@ across chunk steps — the TPU-native replacement for the CUDA wkv kernels
   S_out = exp(total) * S_in + (k * exp(total - cs))^T V
 
 with r~ = r * exp(cs_{i-1}), k~ = k * exp(-cs_j) (log-decays clamped to
-[-1, 0) as in the model code, so exp(-cs) fits fp32 for C <= 64).
+[-1, 0) as in the model code, so exp(-cs) fits fp32 for C <= 64). The
+inclusive prefix sum cs is a matmul with a lower-triangular ones mask (the
+TPU lowering has no cumsum). Every matmul runs at fp32 precision: the
+exp(+-cs) factors reach e^64 and cancel only if the products are exact to
+fp32, which one bf16 MXU pass is not.
 """
 from __future__ import annotations
 
@@ -21,9 +25,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_scr, *,
@@ -38,29 +45,23 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_scr, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)
-    u = u_ref[0, 0].astype(jnp.float32)             # (dh,)
+    u = u_ref[0].astype(jnp.float32)                # (1, dh)
 
-    cs = jnp.cumsum(lw, axis=0)                     # inclusive
-    total = cs[-1]                                  # (dh,)
-    rq = r * jnp.exp(cs - lw)                       # r~ (decay to i-1)
-    kk = k * jnp.exp(-cs)                           # k~
-    att = jax.lax.dot_general(rq, kk, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    att = jnp.where(ii > jj, att, 0.0)
-    diag = jnp.sum(r * (u[None, :] * k), axis=1)    # (C,)
-    y = (jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-         + diag[:, None] * v
-         + jax.lax.dot_general(rq, s_scr[...], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32))
+    cs = _dot(jnp.where(ii >= jj, 1.0, 0.0), lw, ((1,), (0,)))  # inclusive
+    total = cs[chunk - 1:chunk]                     # (1, dh)
+    rq = r * jnp.exp(cs - lw)                       # r~ (decay to i-1)
+    kk = k * jnp.exp(-cs)                           # k~
+    att = jnp.where(ii > jj, _dot(rq, kk, ((1,), (1,))), 0.0)
+    diag = jnp.sum(r * (u * k), axis=1, keepdims=True)   # (C, 1)
+    y = (_dot(att, v, ((1,), (0,))) + diag * v
+         + _dot(rq, s_scr[...], ((1,), (0,))))
     y_ref[0] = y.astype(y_ref.dtype)
 
-    kdec = k * jnp.exp(total[None, :] - cs)
-    s_scr[...] = (s_scr[...] * jnp.exp(total)[:, None]
-                  + jax.lax.dot_general(kdec, v, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32))
+    kdec = k * jnp.exp(total - cs)
+    s_scr[...] = (s_scr[...] * jnp.exp(total).T
+                  + _dot(kdec, v, ((0,), (0,))))
 
 
 def wkv6_fwd(r: jax.Array, k: jax.Array, v: jax.Array, lw: jax.Array,
@@ -81,7 +82,7 @@ def wkv6_fwd(r: jax.Array, k: jax.Array, v: jax.Array, lw: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bh, s, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, lw, u[:, None, :])

@@ -16,22 +16,21 @@ def _fold(x):                                      # (B,S,H,d) -> (BH,S,d)
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def wkv6(r, k, v, lw, u, chunk: int = 64):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def wkv6(r, k, v, lw, u, chunk: int = 64, interpret: bool = False):
     """r/k/v/lw: (B, S, H, dh); u: (H, dh)."""
     b, s, h, dh = r.shape
-    interpret = jax.default_backend() != "tpu"
     u_full = jnp.broadcast_to(u[None], (b, h, dh)).reshape(b * h, dh)
     y = wkv6_fwd(_fold(r), _fold(k), _fold(v), _fold(lw), u_full,
                  chunk=chunk, interpret=interpret)
     return y.reshape(b, h, s, dh).transpose(0, 2, 1, 3)
 
 
-def _fwd(r, k, v, lw, u, chunk):
-    return wkv6(r, k, v, lw, u, chunk), (r, k, v, lw, u)
+def _fwd(r, k, v, lw, u, chunk, interpret):
+    return wkv6(r, k, v, lw, u, chunk, interpret), (r, k, v, lw, u)
 
 
-def _bwd(chunk, res, g):
+def _bwd(chunk, interpret, res, g):
     r, k, v, lw, u = res
 
     def ref(r, k, v, lw, u):

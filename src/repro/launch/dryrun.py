@@ -1,5 +1,7 @@
 import os
+# CPU-only compile tool: virtual host devices, never the accelerator
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -55,9 +57,8 @@ from repro.perfmodel.machine import TPU_V5E, TPU_V5E_HBM_GB
 from repro.perfmodel.memory import structural_memory
 from repro.perfmodel.traffic import hbm_traffic
 from repro.perfmodel.model_flops import model_flops, param_count
-from repro.train.train_step import TrainState, build_train_step, \
-    init_train_state
-from repro.train.optimizer import OptState
+from repro.train.train_step import build_train_step, init_train_state, \
+    train_state_specs
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results")
@@ -149,20 +150,7 @@ def lower_cell(cfg: ModelConfig, run: RunConfig, mesh,
     if shape.step == StepKind.TRAIN:
         state_shape = jax.eval_shape(
             lambda r: init_train_state(model, run, r), rng)
-        pspecs = param_specs(state_shape.params, cfg, run.mesh,
-                             run.fsdp and run.zero_stage >= 3,
-                             run.fsdp_over_pods, run.moe_full_ep,
-                             run.parallelism)
-        # ZeRO-1: optimizer moments sharded even when params stay resident
-        ospecs = param_specs(state_shape.params, cfg, run.mesh, run.fsdp,
-                             run.fsdp_over_pods, run.moe_full_ep,
-                             run.parallelism)
-        # error-feedback residual (grad compression) shards like the
-        # optimizer moments: gradient-shaped, per-replica persistent state
-        efspecs = ospecs if state_shape.ef is not None else None
-        state_specs = TrainState(
-            params=pspecs, opt=OptState(step=P(), m=ospecs, v=ospecs),
-            ef=efspecs)
+        state_specs = train_state_specs(state_shape, run)
         state_sds = _with_sharding(state_shape, state_specs, mesh)
         batch_shape = model.input_specs(shape)
         bspecs = batch_specs(batch_shape, run.mesh, shape, run.parallelism)
@@ -210,17 +198,12 @@ def _dp_reduce_elems(cfg: ModelConfig, run: RunConfig) -> Optional[float]:
     model = build_model(cfg, _runtime(run, False, _n_periods(cfg)))
     state_shape = jax.eval_shape(
         lambda r: init_train_state(model, run, r), jax.random.PRNGKey(0))
-    pspecs = param_specs(state_shape.params, cfg, run.mesh,
-                         run.fsdp and run.zero_stage >= 3,
-                         run.fsdp_over_pods, run.moe_full_ep,
-                         run.parallelism)
+    pspecs = train_state_specs(state_shape, run).params
     return dp_grad_reduce_elems(state_shape.params, pspecs, run.mesh)
 
 
 def _costs(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # jax<=0.4.x: one entry per program
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}
 

@@ -1,5 +1,7 @@
 import os
+# CPU-only compile tool: virtual host devices, never the accelerator
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """§Perf hillclimb runner: re-derive a cell's roofline terms under an
 optimization and record baseline vs optimized (results/perf_<cell>.json).

@@ -34,11 +34,6 @@ from repro.dist.axes import _resolve as _resolve_axis
 from repro.dist.axes import current_mesh_axes
 from repro.models.layers import act_fn, dense_init
 
-try:                                    # jax >= 0.6 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Params = Dict[str, Any]
 
 
@@ -230,7 +225,7 @@ def _moe_forward_sharded(p: Params, x: jax.Array, cfg: ModelConfig):
     else:
         sw = (jnp.zeros((1, 1), x.dtype),) * 3
         sw_spec = (P(None, None),) * 3
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), *ew_spec, *sw_spec),
         out_specs=(x_spec, P()))
@@ -336,7 +331,7 @@ def _moe_forward_full_ep(p: Params, x: jax.Array, cfg: ModelConfig):
         return y.reshape(bl, sl, d), aux
 
     ew_spec = tuple(P(("data", "model"), None, None) for _ in range(3))
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), *ew_spec),
         out_specs=(x_spec, P()))

@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import RunConfig, StepKind
 from repro.dist.compression import (
@@ -19,6 +20,7 @@ from repro.dist.compression import (
     decompress_grads,
     init_residual,
 )
+from repro.dist.sharding import param_specs
 from repro.models.model_zoo import Model
 from repro.train.optimizer import (
     OptState,
@@ -46,6 +48,24 @@ def init_train_state(model: Model, run: RunConfig, rng: jax.Array
                       opt=init_opt_state(params, run.optimizer),
                       ef=init_residual(params,
                                        run.optimizer.grad_compression))
+
+
+def train_state_specs(state: TrainState, run: RunConfig) -> TrainState:
+    """``PartitionSpec`` tree for a train state (shapes or arrays) under
+    the production sharding rules of ``run``: params sharded as ZeRO-3
+    asks, optimizer moments (and the error-feedback residual, which is
+    gradient-shaped per-replica state) sharded whenever FSDP is on."""
+    cfg, mesh = run.model, run.mesh
+    pspecs = param_specs(state.params, cfg, mesh,
+                         run.fsdp and run.zero_stage >= 3,
+                         run.fsdp_over_pods, run.moe_full_ep,
+                         run.parallelism)
+    ospecs = param_specs(state.params, cfg, mesh, run.fsdp,
+                         run.fsdp_over_pods, run.moe_full_ep,
+                         run.parallelism)
+    return TrainState(params=pspecs,
+                      opt=OptState(step=P(), m=ospecs, v=ospecs),
+                      ef=ospecs if state.ef is not None else None)
 
 
 def build_train_step(model: Model, run: RunConfig, total_steps: int = 10_000

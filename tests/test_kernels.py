@@ -78,8 +78,8 @@ def test_mamba_scan(b, s, d, n, bd, chunk, dtype):
 def test_lstm_cell(b, d, h, bb, bhid):
     ks = jax.random.split(RNG, 4)
     xh = jax.random.normal(ks[0], (b, d + h))
-    w = jax.random.normal(ks[1], (d + h, h, 4)) * 0.1
-    bias = jax.random.normal(ks[2], (h, 4)) * 0.1
+    w = jax.random.normal(ks[1], (4, d + h, h)) * 0.1
+    bias = jax.random.normal(ks[2], (4, h)) * 0.1
     c = jax.random.normal(ks[3], (b, h))
     h1, c1 = lstm_cell_fwd(xh, w, bias, c, block_b=bb, block_h=bhid,
                            interpret=True)
@@ -97,7 +97,8 @@ def test_flash_attention_vjp_matches_ref():
     v = jax.random.normal(ks[2], (1, 128, 2, 64))
 
     def f(q, k, v):
-        return jnp.sum(flash_attention(q, k, v) ** 2)
+        return jnp.sum(flash_attention(q, k, v, True, 128, 128,
+                                       True) ** 2)
 
     g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
     for t in g:
@@ -116,5 +117,5 @@ def test_wkv6_matches_model_chunked_path():
     y_model, _ = wkv6_chunked(r, k, v, lw, u,
                               jnp.zeros((b, h, dh, dh)), chunk=32)
     from repro.kernels.rwkv6_wkv.ops import wkv6 as wkv6_op
-    y_kernel = wkv6_op(r, k, v, lw, u, 32)
+    y_kernel = wkv6_op(r, k, v, lw, u, 32, True)
     np.testing.assert_allclose(y_kernel, y_model, rtol=5e-4, atol=5e-4)
