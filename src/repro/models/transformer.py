@@ -156,8 +156,6 @@ def block_forward(p: Params, x: jax.Array, cfg: ModelConfig,
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
     chunk = _auto_chunk(rt, x.shape[1])
-    h = rms_norm(x, p["mixer_norm"], cfg.norm_eps)
-    mc = cache.get("mixer") if cache is not None else None
     def _name(t: jax.Array) -> jax.Array:
         # post-TP-collective intermediates; the save_boundaries remat
         # policy keeps them so recompute skips re-executing the
@@ -166,43 +164,50 @@ def block_forward(p: Params, x: jax.Array, cfg: ModelConfig,
             return jax.ad_checkpoint.checkpoint_name(t, "block_boundary")
         return t
 
-    if mixer_kind == BK.ATTENTION:
-        y, c = attn.gqa_forward(p["mixer"], h, cfg, positions=positions,
-                                causal=causal, chunk=chunk,
-                                unroll=rt.attn_unroll, cache=mc,
-                                cache_index=cache_index,
-                                return_kv=return_cache)
-    elif mixer_kind == BK.MLA:
-        y, c = attn.mla_forward(p["mixer"], h, cfg, positions=positions,
-                                chunk=chunk, unroll=rt.attn_unroll, cache=mc,
-                                cache_index=cache_index,
-                                return_kv=return_cache)
-    elif mixer_kind == BK.MAMBA:
-        y, c = mb.mamba_forward(p["mixer"], h, cfg, cache=mc,
-                                return_state=return_cache)
-    else:
-        y, c = rw.time_mix_forward(p["mixer"], h, cfg, cache=mc,
-                                   return_state=return_cache)
-    if c is not None:
-        new_cache["mixer"] = c
-    x = x + _name(y)
-
-    h = rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-    fc = cache.get("ffn") if cache is not None else None
-    if ffn_kind == BK.DENSE_FFN:
-        y = ffn_forward(p["ffn"], h, cfg)
-    elif ffn_kind == BK.MOE_FFN:
-        y, aux = moe_mod.moe_forward(p["ffn"], h, cfg, rt.tp_degree,
-                                     rt.moe_full_ep)
-    else:
-        y, c2 = rw.channel_mix_forward(p["ffn"], h, cfg,
-                                       cache=fc if fc else None,
+    # the named scopes only name the HLO ops (op_name metadata), so that a
+    # profile splits device time into mixer and FFN, forward and backward
+    with jax.named_scope("mixer"):
+        h = rms_norm(x, p["mixer_norm"], cfg.norm_eps)
+        mc = cache.get("mixer") if cache is not None else None
+        if mixer_kind == BK.ATTENTION:
+            y, c = attn.gqa_forward(p["mixer"], h, cfg, positions=positions,
+                                    causal=causal, chunk=chunk,
+                                    unroll=rt.attn_unroll, cache=mc,
+                                    cache_index=cache_index,
+                                    return_kv=return_cache)
+        elif mixer_kind == BK.MLA:
+            y, c = attn.mla_forward(p["mixer"], h, cfg, positions=positions,
+                                    chunk=chunk, unroll=rt.attn_unroll,
+                                    cache=mc, cache_index=cache_index,
+                                    return_kv=return_cache)
+        elif mixer_kind == BK.MAMBA:
+            y, c = mb.mamba_forward(p["mixer"], h, cfg, cache=mc,
+                                    return_state=return_cache)
+        else:
+            y, c = rw.time_mix_forward(p["mixer"], h, cfg, cache=mc,
                                        return_state=return_cache)
-        if c2 is not None:
-            new_cache["ffn"] = c2
-    if "ffn" not in new_cache:
-        new_cache["ffn"] = {}
-    return x + _name(y), new_cache, aux
+        if c is not None:
+            new_cache["mixer"] = c
+        x = x + _name(y)
+
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, p["ffn_norm"], cfg.norm_eps)
+        fc = cache.get("ffn") if cache is not None else None
+        if ffn_kind == BK.DENSE_FFN:
+            y = ffn_forward(p["ffn"], h, cfg)
+        elif ffn_kind == BK.MOE_FFN:
+            y, aux = moe_mod.moe_forward(p["ffn"], h, cfg, rt.tp_degree,
+                                         rt.moe_full_ep)
+        else:
+            y, c2 = rw.channel_mix_forward(p["ffn"], h, cfg,
+                                           cache=fc if fc else None,
+                                           return_state=return_cache)
+            if c2 is not None:
+                new_cache["ffn"] = c2
+        if "ffn" not in new_cache:
+            new_cache["ffn"] = {}
+        x = x + _name(y)
+    return x, new_cache, aux
 
 
 # ---------------------------------------------------------------------------
@@ -253,11 +258,13 @@ class TransformerLM:
 
     # -- helpers ----------------------------------------------------------
     def _embed(self, p: Params, batch: Dict[str, jax.Array]) -> jax.Array:
-        x = p["embed"][batch["tokens"]].astype(self.rt.compute_dtype)
-        x = constrain(x, "dp", None, None)
-        if self.cfg.frontend == "image_patches" and "patches" in batch:
-            x = jnp.concatenate(
-                [batch["patches"].astype(self.rt.compute_dtype), x], axis=1)
+        with jax.named_scope("embed"):
+            x = p["embed"][batch["tokens"]].astype(self.rt.compute_dtype)
+            x = constrain(x, "dp", None, None)
+            if self.cfg.frontend == "image_patches" and "patches" in batch:
+                x = jnp.concatenate(
+                    [batch["patches"].astype(self.rt.compute_dtype), x],
+                    axis=1)
         return x
 
     def _head(self, p: Params, x: jax.Array) -> jax.Array:
@@ -318,8 +325,9 @@ class TransformerLM:
             # image positions carry no LM loss
             pad = jnp.full(batch["patches"].shape[:2], -1, labels.dtype)
             labels = jnp.concatenate([pad, labels], axis=1)
-        logits = self._head(p, x)
-        loss = softmax_xent(logits, labels, cfg.vocab_size)
+        with jax.named_scope("head_loss"):
+            logits = self._head(p, x)
+            loss = softmax_xent(logits, labels, cfg.vocab_size)
         metrics = {"xent": loss, "aux": aux}
         if cfg.mtp_depth and "mtp" in p:
             loss_mtp = self._mtp_loss(p, x, batch, positions)

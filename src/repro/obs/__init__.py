@@ -54,6 +54,7 @@ from repro.obs.trace import (
     get_tracer,
     set_tracer,
     span,
+    step_span,
     traced,
     tracing_enabled,
 )
@@ -65,7 +66,7 @@ __all__ = [
     "cell_collective_projection", "collective_projection_report",
     "bucket_bound", "disable", "enable", "enable_tracing", "event",
     "export_all", "get_registry", "get_sink", "get_tracer", "metrics",
-    "serve_http", "set_sink", "set_tracer", "span", "traced",
+    "serve_http", "set_sink", "set_tracer", "span", "step_span", "traced",
     "tracing_enabled",
 ]
 

@@ -4,7 +4,12 @@ SeqPoint's premise is that detailed profiling is too expensive to run on
 every iteration (paper §I) — so the tracer must cost nothing when it is off
 and almost nothing when it is on. Disabled, ``span()`` returns one shared
 no-op context manager: no clock read, no allocation, no lock. Enabled, each
-span is a single perf_counter pair plus one dict appended under a lock.
+span is a single perf_counter pair plus one dict appended under a lock, and
+it also enters a ``jax.profiler.TraceAnnotation`` of the same name, so while
+the JAX profiler records, the span sits in its trace on the host plane, on
+the profiler's clock beside the device's operations (a no-op otherwise).
+``step_span`` writes a ``StepTraceAnnotation`` instead, which gives the
+profiler's step view the same step number.
 
 Spans nest via a thread-local stack, so concurrent threads (e.g. the async
 checkpoint writer) interleave correctly in the exported trace. Export is the
@@ -21,6 +26,8 @@ import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 class _NullSpan:
@@ -42,31 +49,42 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "step_num",
+                 "annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
+                 step_num: Optional[int] = None):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0.0
         self.depth = 0
+        self.step_num = step_num
+        self.annotation: Any = None
 
     def set(self, **args: Any) -> "_Span":
         """Attach attributes after entry (e.g. a result computed inside)."""
         self.args.update(args)
         return self
 
+    # The clock is read first on entry and last before recording on exit,
+    # so the span's own bookkeeping (the annotation included) falls inside
+    # it and consecutive spans leave almost no host time uncovered.
     def __enter__(self) -> "_Span":
+        self.t0 = time.perf_counter()
         stack = self.tracer._stack()
         self.depth = len(stack)
         stack.append(self.name)
-        self.t0 = time.perf_counter()
+        self.annotation = TraceAnnotation(self.name) \
+            if self.step_num is None \
+            else StepTraceAnnotation(self.name, step_num=self.step_num)
+        self.annotation.__enter__()
         return self
 
     def __exit__(self, *exc: object) -> bool:
-        t1 = time.perf_counter()
+        self.annotation.__exit__(*exc)
         self.tracer._stack().pop()
-        self.tracer._record(self, t1)
+        self.tracer._record(self, time.perf_counter())
         return False
 
 
@@ -105,6 +123,13 @@ class Tracer:
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, args)
+
+    def step_span(self, name: str, step: int, **args: Any):
+        """A span around one training step: ``step`` is recorded as an
+        attribute and as the profiler's step number."""
+        if not self.enabled:
+            return NULL_SPAN
+        return _Span(self, name, dict(args, step=step), step_num=step)
 
     def current_span(self) -> Optional[str]:
         stack = self._stack()
@@ -158,6 +183,11 @@ def span(name: str, **args: Any):
     if not tracer.enabled:
         return NULL_SPAN
     return _Span(tracer, name, args)
+
+
+def step_span(name: str, step: int, **args: Any):
+    """``with step_span("train/step", 7): ...`` on the global tracer."""
+    return _TRACER.step_span(name, step, **args)
 
 
 def traced(name: Optional[str] = None) -> Callable:

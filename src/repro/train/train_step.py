@@ -122,8 +122,9 @@ def build_train_step(model: Model, run: RunConfig, total_steps: int = 10_000
                 ef_new = err
 
         lr = lr_fn(state.opt.step)
-        new_params, new_opt, opt_metrics = adamw_update(
-            grads, state.opt, state.params, run.optimizer, lr)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                grads, state.opt, state.params, run.optimizer, lr)
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return TrainState(new_params, new_opt, ef_new), metrics
 

@@ -172,31 +172,36 @@ class Trainer:
                         label="ckpt_save")
             obs.event("checkpoint", step=start, mode="initial")
         while step < end:
-            # iterator position BEFORE the fetch: the identity of the batch
-            # about to run, and the resume point if this step is preempted
-            pre_fetch = self.data.state()
-            batch_key = (pre_fetch["epoch"], pre_fetch["batch_index"])
-            if skiplist.should_skip(batch_key):
-                next(it)                              # discard poison batch
-                report.skipped_batches += 1
-                mreg.counter("train_skipped_batches_total").inc()
-                obs.event("poison_batch_skipped", step=step,
-                          epoch=batch_key[0], batch_index=batch_key[1])
-                continue
             new_state = None
             try:
-                # heartbeat interval: raises PeerLossFault once the tracker
-                # confirms a host lost (tier-4 re-mesh arm below)
-                self.cluster.pulse(step)
-                with obs.span("train/step", step=step) as step_span:
+                with obs.span("train/pulse"):
+                    # iterator position BEFORE the fetch: the identity of
+                    # the batch about to run, and the resume point if this
+                    # step is preempted
+                    pre_fetch = self.data.state()
+                    batch_key = (pre_fetch["epoch"], pre_fetch["batch_index"])
+                    skip = skiplist.should_skip(batch_key)
+                    if not skip:
+                        # heartbeat interval: raises PeerLossFault once the
+                        # tracker confirms a host lost (tier-4 re-mesh arm)
+                        self.cluster.pulse(step)
+                if skip:
+                    next(it)                          # discard poison batch
+                    report.skipped_batches += 1
+                    mreg.counter("train_skipped_batches_total").inc()
+                    obs.event("poison_batch_skipped", step=step,
+                              epoch=batch_key[0], batch_index=batch_key[1])
+                    continue
+                with obs.step_span("train/step", step) as step_span:
                     with obs.span("train/data_fetch"):
                         def fetch():
                             faults.fire("data_fetch", step)
                             return next(it)
                         tokens, labels, sl = self._retry(fetch,
                                                          label="data_fetch")
-                        batch = {"tokens": jax.numpy.asarray(tokens),
-                                 "labels": jax.numpy.asarray(labels)}
+                        with obs.span("train/h2d"):
+                            batch = {"tokens": jax.numpy.asarray(tokens),
+                                     "labels": jax.numpy.asarray(labels)}
                     step_span.set(sl=sl)
                     faults.fire("preempt", step)
                     t0 = self.timer()
@@ -206,13 +211,14 @@ class Trainer:
                         jax.block_until_ready(metrics["loss"])
                     dt = self.timer() - t0
                     dt += faults.delay("straggler", step)
-                    loss = faults.corrupt("nan_loss", step,
-                                          float(metrics["loss"]))
-                    check_finite(loss, name="loss", step=step)
-                    if self.policy.check_grads and "grad_norm" in metrics:
-                        check_finite(float(metrics["grad_norm"]),
-                                     name="grad_norm", step=step)
-                    self.divergence.update(loss, step=step)
+                    with obs.span("train/readback"):
+                        loss = faults.corrupt("nan_loss", step,
+                                              float(metrics["loss"]))
+                        check_finite(loss, name="loss", step=step)
+                        if self.policy.check_grads and "grad_norm" in metrics:
+                            check_finite(float(metrics["grad_norm"]),
+                                         name="grad_norm", step=step)
+                        self.divergence.update(loss, step=step)
             except PreemptionFault:
                 return self._handle_preemption(step, start, state,
                                                pre_fetch, report)
@@ -244,28 +250,26 @@ class Trainer:
                 it = iter(self.data)      # regenerate from restored position
                 continue
             # -- step accepted ------------------------------------------
-            state = new_state
-            verdict = self.watchdog.observe(sl, dt)
-            if verdict.is_straggler:
-                report.stragglers += 1
-                mreg.counter("train_stragglers_total").inc()
-                obs.event("straggler", step=step, sl=sl, dt=dt,
-                          baseline=verdict.baseline,
-                          factor=self.watchdog.factor)
-            report.losses.append(loss)
-            report.step_times.append(dt)
-            tp_bytes = tp_activation_wire_bytes(
-                self.run.model, self.run.shape.global_batch, sl, tp_deg)
-            self.epoch_log.append(sl, dt, dp_wire_bytes=dp_bytes,
-                                  tp_wire_bytes=tp_bytes)
-            mreg.counter("train_steps_total").inc()
-            mreg.histogram("train_step_time_s", sl=sl).observe(dt)
-            mreg.histogram("train_padded_sl").observe(sl)
-            mreg.gauge("train_dp_wire_bytes").set(dp_bytes)
-            mreg.histogram("train_tp_wire_bytes", sl=sl).observe(tp_bytes)
-            step += 1
-            if self.ckpt is not None and step % self.ckpt_every == 0:
-                self._save_periodic(step, state)
+            with obs.span("train/accept"):
+                state = new_state
+                verdict = self.watchdog.observe(sl, dt)
+                if verdict.is_straggler:
+                    report.stragglers += 1
+                    mreg.counter("train_stragglers_total").inc()
+                    obs.event("straggler", step=step, sl=sl, dt=dt,
+                              baseline=verdict.baseline,
+                              factor=self.watchdog.factor)
+                report.losses.append(loss)
+                report.step_times.append(dt)
+                tp_bytes = tp_activation_wire_bytes(
+                    self.run.model, self.run.shape.global_batch, sl, tp_deg)
+                self.epoch_log.append(sl, dt, dp_wire_bytes=dp_bytes,
+                                      tp_wire_bytes=tp_bytes)
+                mreg.counter("train_steps_total").inc()
+                mreg.histogram("train_step_time_s", sl=sl).observe(dt)
+                step += 1
+                if self.ckpt is not None and step % self.ckpt_every == 0:
+                    self._save_periodic(step, state)
         if self.ckpt is not None:
             with obs.span("train/checkpoint_final", step=end):
                 self._wait_ckpt()

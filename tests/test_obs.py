@@ -309,38 +309,85 @@ def test_cell_projection_micro_counted_normalizes_rolled_scan():
 
 # ------------------------------------------------------- end-to-end trainer
 
+TRAIN_SPANS = ("train/pulse", "train/step", "train/data_fetch", "train/h2d",
+               "train/step_fn", "train/block_until_ready", "train/readback",
+               "train/accept")
 
-def test_trainer_emits_spans_metrics_and_straggler_events(tracer, sink):
+
+def _tiny_run(arch="starcoder2-3b", **over):
     from repro.configs import MeshConfig, OptimizerConfig, RunConfig, \
         ShapeConfig, StepKind, smoke_config
+
+    cfg = smoke_config(arch).with_overrides(
+        num_layers=2, d_model=64, vocab_size=256, **over)
+    shape = ShapeConfig("tiny", seq_len=32, global_batch=8,
+                        step=StepKind.TRAIN)
+    return RunConfig(model=cfg, shape=shape,
+                     mesh=MeshConfig(shape=(1,), axes=("data",)),
+                     optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2),
+                     param_dtype="float32", compute_dtype="float32")
+
+
+def _tiny_trainer(**kw):
     from repro.data.batching import DataIterator
     from repro.data.synthetic import IWSLT_LIKE
     from repro.models import Runtime, build_model
     from repro.train.trainer import Trainer
 
-    obs.metrics.reset()
-    cfg = smoke_config("starcoder2-3b").with_overrides(
-        num_layers=2, d_model=64, d_ff=128, vocab_size=256)
-    shape = ShapeConfig("tiny", seq_len=32, global_batch=8,
-                        step=StepKind.TRAIN)
-    run = RunConfig(model=cfg, shape=shape,
-                    mesh=MeshConfig(shape=(1,), axes=("data",)),
-                    optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2),
-                    param_dtype="float32", compute_dtype="float32")
+    run = _tiny_run(d_ff=128)
     data = DataIterator(IWSLT_LIKE, samples_per_epoch=256, batch_size=8,
-                        vocab_size=cfg.vocab_size, granularity=8, seed=1)
-    model = build_model(cfg, Runtime.from_run(run))
-    tr = Trainer(model, run, data, straggler_factor=1e-9, total_steps=8)
+                        vocab_size=run.model.vocab_size, granularity=8,
+                        seed=1)
+    model = build_model(run.model, Runtime.from_run(run))
+    return Trainer(model, run, data, **kw)
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the host plane's events as
+    (name, start ns, duration ns, stats), in start order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    return sorted(((e.name, float(e.start_ns), float(e.duration_ns),
+                    dict(e.stats) if e.name.startswith("train/") else {})
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:CPU")
+                   for line in plane.lines for e in line.events),
+                  key=lambda ev: ev[1])
+
+
+def _warm_trainer(steps):
+    """A tiny trainer that has compiled every program of its next
+    ``steps`` steps."""
+    tr = _tiny_trainer(total_steps=8)
+    pos = tr.data.state()
+    tr.train(steps)
+    tr.data.restore(pos)
+    return tr
+
+
+def test_trainer_emits_spans_metrics_and_straggler_events(tracer, sink):
+    obs.metrics.reset()
+    tr = _tiny_trainer(straggler_factor=1e-9, total_steps=8)
     rep = tr.train(5)
     assert rep.steps == 5
 
     names = [e["name"] for e in tracer.events]
-    for expected in ("train/step", "train/data_fetch", "train/step_fn",
-                     "train/block_until_ready"):
+    for expected in TRAIN_SPANS:
         assert names.count(expected) == 5, expected
     # step spans carry the padded SL attribute
     step_evs = [e for e in tracer.events if e["name"] == "train/step"]
     assert all("sl" in e["args"] for e in step_evs)
+    assert [e["args"]["step"] for e in step_evs] == list(range(5))
 
     sink.flush()
     evs = [json.loads(l) for l in open(sink.path)]
@@ -359,29 +406,75 @@ def test_trainer_emits_spans_metrics_and_straggler_events(tracer, sink):
     obs.metrics.reset()
 
 
+def test_trainer_spans_reach_the_profiler_trace(tracer, tmp_path):
+    """Enabled spans are also TraceMe annotations: each of the loop's spans
+    sits on the profiler's host plane once per step, ``train/step`` with
+    its step number, and the plane's spans are the tracer's own."""
+    tr = _warm_trainer(4)
+    tracer.clear()
+    host = _profiled(tmp_path, lambda: tr.train(4))
+    on_plane = [ev for ev in host if ev[0].startswith("train/")]
+    for name in TRAIN_SPANS:
+        assert sum(ev[0] == name for ev in on_plane) == 4, name
+    assert [st["step_num"] for n, _, _, st in on_plane
+            if n == "train/step"] == [0, 1, 2, 3]
+    mine = sorted(tracer.events, key=lambda e: e["ts"])
+    assert [e["name"] for e in mine] == [ev[0] for ev in on_plane]
+    # the tracer's clock reads enclose the annotation, tightly
+    for e, (_, _, dur_ns, _) in zip(mine, on_plane):
+        assert dur_ns * 1e-3 <= 1.001 * e["dur"] + 5.0, e["name"]
+        assert e["dur"] <= 1.1 * dur_ns * 1e-3 + 2e3, e["name"]
+
+
+def test_disabled_tracer_writes_nothing_to_the_profiler_trace(tmp_path):
+    assert not obs.tracing_enabled()
+    assert obs.span("train/step") is NULL_SPAN
+    assert obs.step_span("train/step", 3) is NULL_SPAN
+    tr = _warm_trainer(2)
+    host = _profiled(tmp_path, lambda: tr.train(2))
+    assert not [ev for ev in host if ev[0].startswith("train/")]
+    assert obs.get_tracer().events == []
+
+
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "rwkv6-3b"])
+def test_train_step_ops_carry_the_layer_scopes(arch):
+    """The compiled step names its ops by layer kind: embedding, mixer
+    (attention or RWKV time-mix), FFN (or channel-mix), head and loss, and
+    the optimizer, forward and backward alike."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import Runtime, build_model
+    from repro.train.train_step import build_train_step, init_train_state
+
+    run = _tiny_run(arch)
+    model = build_model(run.model, Runtime.from_run(run))
+    state = init_train_state(model, run, jax.random.PRNGKey(0))
+    batch = {k: jnp.zeros((8, 32), jnp.int32) for k in ("tokens", "labels")}
+    hlo = jax.jit(build_train_step(model, run)).lower(
+        state, batch).compile().as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', hlo)]
+
+    def bare(part):                  # transpose(jvp(ffn)) -> ffn
+        while re.fullmatch(r"[\w-]+\(.*\)", part):
+            part = part[part.index("(") + 1:-1]
+        return part
+
+    for scope in ("embed", "mixer", "ffn", "head_loss", "optimizer"):
+        assert any(scope in map(bare, parts) for parts in paths), scope
+    for scope in ("embed", "mixer", "ffn", "head_loss"):
+        assert any(scope in map(bare, parts) and
+                   any(p.startswith("transpose(") for p in parts)
+                   for parts in paths), f"no backward op in {scope}"
+
+
 def test_trainer_disabled_obs_keeps_log_identical():
     """With obs off (default), training still logs the epoch normally and
     no trace events or sink writes happen."""
-    from repro.configs import MeshConfig, OptimizerConfig, RunConfig, \
-        ShapeConfig, StepKind, smoke_config
-    from repro.data.batching import DataIterator
-    from repro.data.synthetic import IWSLT_LIKE
-    from repro.models import Runtime, build_model
-    from repro.train.trainer import Trainer
-
     assert obs.get_sink() is None and not obs.tracing_enabled()
-    cfg = smoke_config("starcoder2-3b").with_overrides(
-        num_layers=2, d_model=64, d_ff=128, vocab_size=256)
-    shape = ShapeConfig("tiny", seq_len=32, global_batch=8,
-                        step=StepKind.TRAIN)
-    run = RunConfig(model=cfg, shape=shape,
-                    mesh=MeshConfig(shape=(1,), axes=("data",)),
-                    optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2),
-                    param_dtype="float32", compute_dtype="float32")
-    data = DataIterator(IWSLT_LIKE, samples_per_epoch=256, batch_size=8,
-                        vocab_size=cfg.vocab_size, granularity=8, seed=1)
-    model = build_model(cfg, Runtime.from_run(run))
-    tr = Trainer(model, run, data, total_steps=4)
+    tr = _tiny_trainer(total_steps=4)
     rep = tr.train(3)
     assert rep.steps == 3 and tr.epoch_log.num_iterations == 3
     assert obs.get_tracer().events == []
