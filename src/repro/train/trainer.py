@@ -23,6 +23,23 @@ conditions, so the step loop is wrapped in a recovery ladder
   mesh over the surviving hosts, re-shards the restored state, and resumes
   in-process — the fourth recovery tier;
 * a per-SL running-median watchdog flags stragglers (and injected ones).
+
+The loop keeps one step in flight. Iteration *i* fetches batch *i* and
+dispatches step *i* on the (not yet ready) state step *i−1* returned, and
+only then waits for step *i−1*'s loss, runs the guards on it and accepts
+it, so the device has the next step queued while the host does its
+bookkeeping. The loop drains — waits for the step in flight and accepts it
+before dispatching another — where the host needs the state or the step
+settled: at a periodic checkpoint (the snapshot must precede the next
+step's donation), at the end of ``train()``, on a preemption or a peer
+loss, and on any exception that leaves ``train()``. A guard violation found
+on step *i−1* discards step *i* and rolls back as above, charging step
+*i−1*'s batch. Each step's ``dt`` (``EpochLog``, watchdog,
+``report.step_times``) is completion to completion: the timer is read when
+the wait on a step returns, and once more at dispatch when nothing was in
+flight. With the device the bottleneck, that is the step's device time.
+The ``train_steps_overlapped_total`` and ``train_pipeline_drains_total``
+(``reason``) counters show how often the overlap engaged.
 """
 from __future__ import annotations
 
@@ -80,6 +97,17 @@ class TrainerReport:
     lost_hosts: list = field(default_factory=list)
 
 
+@dataclass
+class _InFlight:
+    """A dispatched step whose loss the host has not read back yet."""
+    step: int
+    key: Tuple[int, int]             # (epoch, batch_index) of its batch
+    sl: int
+    metrics: Dict[str, jax.Array]
+    dp_bytes: float
+    tp_bytes: float
+
+
 class Trainer:
     def __init__(self, model: Model, run: RunConfig, data: DataIterator, *,
                  ckpt_dir: Optional[str] = None, ckpt_every: int = 50,
@@ -104,6 +132,7 @@ class Trainer:
         self.step_fn = jax.jit(build_train_step(model, run, total_steps),
                                donate_argnums=0)
         self.epoch_log = EpochLog(meta={"model": run.model.name})
+        self._t_last = 0.0           # timer reading that starts the next dt
 
     # ------------------------------------------------------------------
     def _extra(self, step: int) -> dict:
@@ -161,9 +190,10 @@ class Trainer:
                   num_steps=num_steps, dp_degree=dp_deg, tp_degree=tp_deg)
         mreg = obs.metrics
         skiplist = self.skiplist
-        rollbacks = 0
         end = start + num_steps
-        step = start
+        step = start                  # the next step to dispatch
+        # the dispatched step not yet accepted; ``state`` is its output
+        pending: Optional[_InFlight] = None
         # rollback safety net: guarantee a restorable checkpoint exists
         # before the first optimizer step can fail
         if self.ckpt is not None and self.ckpt.latest_step() is None:
@@ -171,105 +201,114 @@ class Trainer:
                                                extra=self._extra(start)),
                         label="ckpt_save")
             obs.event("checkpoint", step=start, mode="initial")
-        while step < end:
-            new_state = None
-            try:
-                with obs.span("train/pulse"):
-                    # iterator position BEFORE the fetch: the identity of
-                    # the batch about to run, and the resume point if this
-                    # step is preempted
-                    pre_fetch = self.data.state()
-                    batch_key = (pre_fetch["epoch"], pre_fetch["batch_index"])
-                    skip = skiplist.should_skip(batch_key)
-                    if not skip:
-                        # heartbeat interval: raises PeerLossFault once the
-                        # tracker confirms a host lost (tier-4 re-mesh arm)
-                        self.cluster.pulse(step)
-                if skip:
-                    next(it)                          # discard poison batch
-                    report.skipped_batches += 1
-                    mreg.counter("train_skipped_batches_total").inc()
-                    obs.event("poison_batch_skipped", step=step,
-                              epoch=batch_key[0], batch_index=batch_key[1])
-                    continue
-                with obs.step_span("train/step", step) as step_span:
-                    with obs.span("train/data_fetch"):
-                        def fetch():
-                            faults.fire("data_fetch", step)
-                            return next(it)
-                        tokens, labels, sl = self._retry(fetch,
-                                                         label="data_fetch")
-                        with obs.span("train/h2d"):
-                            batch = {"tokens": jax.numpy.asarray(tokens),
-                                     "labels": jax.numpy.asarray(labels)}
-                    step_span.set(sl=sl)
-                    faults.fire("preempt", step)
-                    t0 = self.timer()
-                    with obs.span("train/step_fn", sl=sl):
-                        new_state, metrics = self.step_fn(state, batch)
-                    with obs.span("train/block_until_ready"):
-                        jax.block_until_ready(metrics["loss"])
-                    dt = self.timer() - t0
-                    dt += faults.delay("straggler", step)
-                    with obs.span("train/readback"):
-                        loss = faults.corrupt("nan_loss", step,
-                                              float(metrics["loss"]))
-                        check_finite(loss, name="loss", step=step)
-                        if self.policy.check_grads and "grad_norm" in metrics:
-                            check_finite(float(metrics["grad_norm"]),
-                                         name="grad_norm", step=step)
-                        self.divergence.update(loss, step=step)
-            except PreemptionFault:
-                return self._handle_preemption(step, start, state,
-                                               pre_fetch, report)
-            except PeerLossFault as e:
-                mreg.counter("train_peer_losses_total").inc(len(e.hosts))
-                obs.event("peer_lost", step=step, hosts=sorted(e.hosts),
-                          tick=e.tick)
-                if self.ckpt is None \
-                        or report.remeshes >= self.policy.max_remeshes:
-                    raise
-                state, step = self._remesh(e, step, start, state,
-                                           pre_fetch, report)
-                dp_deg, tp_deg, dp_bytes = self._comm_profile(state)
-                it = iter(self.data)  # regenerate from restored position
-                continue
-            except GuardViolation as e:
-                report.guard_violations += 1
-                mreg.counter("train_guard_violations_total").inc()
-                obs.event("guard_violation", step=step, error=str(e),
-                          epoch=batch_key[0], batch_index=batch_key[1])
-                if self.ckpt is None or rollbacks >= self.policy.max_rollbacks:
-                    raise
-                rollbacks += 1
-                report.rollbacks += 1
-                now_poison = skiplist.record_failure(batch_key)
-                state, step = self._rollback(
-                    new_state if new_state is not None else state,
-                    start, report, poison=now_poison)
-                it = iter(self.data)      # regenerate from restored position
-                continue
-            # -- step accepted ------------------------------------------
-            with obs.span("train/accept"):
-                state = new_state
-                verdict = self.watchdog.observe(sl, dt)
-                if verdict.is_straggler:
-                    report.stragglers += 1
-                    mreg.counter("train_stragglers_total").inc()
-                    obs.event("straggler", step=step, sl=sl, dt=dt,
-                              baseline=verdict.baseline,
-                              factor=self.watchdog.factor)
-                report.losses.append(loss)
-                report.step_times.append(dt)
-                tp_bytes = tp_activation_wire_bytes(
-                    self.run.model, self.run.shape.global_batch, sl, tp_deg)
-                self.epoch_log.append(sl, dt, dp_wire_bytes=dp_bytes,
-                                      tp_wire_bytes=tp_bytes)
-                mreg.counter("train_steps_total").inc()
-                mreg.histogram("train_step_time_s", sl=sl).observe(dt)
-                step += 1
-                if self.ckpt is not None and step % self.ckpt_every == 0:
-                    self._save_periodic(step, state)
+        try:
+            while step < end or pending is not None:
+                nxt = None
+                try:
+                    if pending is not None and (step == end or (
+                            self.ckpt is not None
+                            and step % self.ckpt_every == 0)):
+                        # the periodic save snapshots the state, so it runs
+                        # before the next step donates it
+                        self._drain(pending, "end" if step == end
+                                    else "checkpoint", report)
+                        pending = None
+                        if step == end:
+                            break
+                        self._save_periodic(step, state)
+                    with obs.span("train/pulse"):
+                        # iterator position BEFORE the fetch: the identity
+                        # of the batch about to run, and the resume point if
+                        # this step is preempted
+                        pre_fetch = self.data.state()
+                        batch_key = (pre_fetch["epoch"],
+                                     pre_fetch["batch_index"])
+                        skip = skiplist.should_skip(batch_key)
+                        if not skip:
+                            # heartbeat interval: raises PeerLossFault once
+                            # the tracker confirms a host lost (tier-4
+                            # re-mesh arm)
+                            self.cluster.pulse(step)
+                    if skip:
+                        next(it)                      # discard poison batch
+                        report.skipped_batches += 1
+                        mreg.counter("train_skipped_batches_total").inc()
+                        obs.event("poison_batch_skipped", step=step,
+                                  epoch=batch_key[0],
+                                  batch_index=batch_key[1])
+                        continue
+                    with obs.step_span("train/step", step) as step_span:
+                        with obs.span("train/data_fetch"):
+                            def fetch():
+                                faults.fire("data_fetch", step)
+                                return next(it)
+                            tokens, labels, sl = self._retry(
+                                fetch, label="data_fetch")
+                            with obs.span("train/h2d"):
+                                batch = {
+                                    "tokens": jax.numpy.asarray(tokens),
+                                    "labels": jax.numpy.asarray(labels)}
+                        step_span.set(sl=sl)
+                        faults.fire("preempt", step)
+                        if pending is None:
+                            self._t_last = self.timer()
+                        else:
+                            mreg.counter("train_steps_overlapped_total").inc()
+                        with obs.span("train/step_fn", sl=sl):
+                            state, metrics = self.step_fn(state, batch)
+                        nxt = _InFlight(step, batch_key, sl, metrics, dp_bytes,
+                                        tp_activation_wire_bytes(
+                                            self.run.model,
+                                            self.run.shape.global_batch, sl,
+                                            tp_deg))
+                        if pending is not None:
+                            loss, dt = self._settle(pending)
+                    if pending is not None:
+                        self._accept(pending, loss, dt, report)
+                    pending, step = nxt, step + 1
+                except PreemptionFault:
+                    flight, pending = pending, None
+                    if flight is not None:
+                        try:
+                            self._drain(flight, "preempt", report)
+                        except GuardViolation as e:
+                            state, step = self._guard_rollback(
+                                e, flight, state, start, report)
+                            pre_fetch = self.data.state()
+                    return self._handle_preemption(step, start, state,
+                                                   pre_fetch, report)
+                except PeerLossFault as e:
+                    mreg.counter("train_peer_losses_total").inc(len(e.hosts))
+                    obs.event("peer_lost", step=step, hosts=sorted(e.hosts),
+                              tick=e.tick)
+                    if self.ckpt is None \
+                            or report.remeshes >= self.policy.max_remeshes:
+                        raise
+                    flight, pending = pending, None
+                    if flight is not None:
+                        try:
+                            self._drain(flight, "remesh", report)
+                        except GuardViolation as g:
+                            state, step = self._guard_rollback(
+                                g, flight, state, start, report)
+                    state, step = self._remesh(e, step, start, state, report)
+                    dp_deg, tp_deg, dp_bytes = self._comm_profile(state)
+                    it = iter(self.data)  # regenerate from restored position
+                except GuardViolation as e:
+                    failed, pending = pending, None
+                    if nxt is not None:
+                        # the step dispatched on the failed one's output
+                        mreg.counter("train_pipeline_drains_total",
+                                     reason="guard").inc()
+                    state, step = self._guard_rollback(e, failed, state,
+                                                       start, report)
+                    it = iter(self.data)  # regenerate from restored position
+        except BaseException:
+            # no step may be left running unrecorded (the feed may end the
+            # run by raising, as a benchmark window does)
+            if pending is not None:
+                self._drain(pending, "exception", report)
+            raise
         if self.ckpt is not None:
             with obs.span("train/checkpoint_final", step=end):
                 self._wait_ckpt()
@@ -284,6 +323,71 @@ class Trainer:
                   skipped_batches=report.skipped_batches,
                   total_runtime=self.epoch_log.total_runtime)
         return report
+
+    # ------------------------------------------------------------------
+    def _settle(self, f: _InFlight) -> Tuple[float, float]:
+        """Wait for step ``f``, read its loss back and run the guards.
+
+        Returns (loss, dt): dt runs from the previous step's completion, or
+        from ``f``'s dispatch when nothing was in flight before it."""
+        with obs.span("train/block_until_ready"):
+            jax.block_until_ready(f.metrics["loss"])
+        t = self.timer()
+        dt = t - self._t_last + faults.delay("straggler", f.step)
+        self._t_last = t
+        with obs.span("train/readback"):
+            loss = faults.corrupt("nan_loss", f.step,
+                                  float(f.metrics["loss"]))
+            check_finite(loss, name="loss", step=f.step)
+            if self.policy.check_grads and "grad_norm" in f.metrics:
+                check_finite(float(f.metrics["grad_norm"]),
+                             name="grad_norm", step=f.step)
+            self.divergence.update(loss, step=f.step)
+        return loss, dt
+
+    def _accept(self, f: _InFlight, loss: float, dt: float,
+                report: TrainerReport) -> None:
+        mreg = obs.metrics
+        with obs.span("train/accept"):
+            verdict = self.watchdog.observe(f.sl, dt)
+            if verdict.is_straggler:
+                report.stragglers += 1
+                mreg.counter("train_stragglers_total").inc()
+                obs.event("straggler", step=f.step, sl=f.sl, dt=dt,
+                          baseline=verdict.baseline,
+                          factor=self.watchdog.factor)
+            report.losses.append(loss)
+            report.step_times.append(dt)
+            self.epoch_log.append(f.sl, dt, dp_wire_bytes=f.dp_bytes,
+                                  tp_wire_bytes=f.tp_bytes)
+            mreg.counter("train_steps_total").inc()
+            mreg.histogram("train_step_time_s", sl=f.sl).observe(dt)
+
+    def _drain(self, f: _InFlight, reason: str,
+               report: TrainerReport) -> None:
+        """Settle and accept the step in flight with nothing queued behind
+        it: the host needs the state, or the loop is ending."""
+        obs.metrics.counter("train_pipeline_drains_total",
+                            reason=reason).inc()
+        with obs.span("train/drain", reason=reason, step=f.step):
+            loss, dt = self._settle(f)
+            self._accept(f, loss, dt, report)
+
+    def _guard_rollback(self, e: GuardViolation, f: _InFlight,
+                        like: TrainState, start: int, report: TrainerReport
+                        ) -> Tuple[TrainState, int]:
+        """A guard tripped on step ``f``: count a failure against its batch
+        and roll back, or re-raise when no rollback is left. ``like`` is the
+        newest dispatched output, the one state not yet donated."""
+        report.guard_violations += 1
+        obs.metrics.counter("train_guard_violations_total").inc()
+        obs.event("guard_violation", step=f.step, error=str(e),
+                  epoch=f.key[0], batch_index=f.key[1])
+        if self.ckpt is None or report.rollbacks >= self.policy.max_rollbacks:
+            raise e
+        report.rollbacks += 1
+        poison = self.skiplist.record_failure(f.key)
+        return self._rollback(like, start, report, poison=poison)
 
     # ------------------------------------------------------------------
     def _wait_ckpt(self) -> None:
@@ -335,8 +439,8 @@ class Trainer:
         return state, ckpt_step
 
     def _remesh(self, e: PeerLossFault, step: int, start: int,
-                state: TrainState, pre_fetch_state: Dict[str, int],
-                report: TrainerReport) -> Tuple[TrainState, int]:
+                state: TrainState, report: TrainerReport
+                ) -> Tuple[TrainState, int]:
         """Tier 4: elastic re-mesh after a confirmed peer loss.
 
         Checkpoint (pinned at the batch about to run), shrink the mesh's
@@ -352,7 +456,9 @@ class Trainer:
             # pin the survivors' state before touching the mesh: if the
             # shrink itself fails we can still resume from here
             self._wait_ckpt()
-            extra = pack_train_extra(step, pre_fetch_state, self.epoch_log,
+            # the pulse runs before the fetch: the iterator still points
+            # at the batch of ``step``
+            extra = pack_train_extra(step, self.data.state(), self.epoch_log,
                                      self.skiplist)
             self._retry(lambda: self.ckpt.save(step, state, extra=extra),
                         label="ckpt_save")

@@ -406,6 +406,40 @@ def test_trainer_emits_spans_metrics_and_straggler_events(tracer, sink):
     obs.metrics.reset()
 
 
+@pytest.mark.parametrize("ckpt_every, drains", [
+    (None, [("end", 11)]),
+    (4, [("checkpoint", 3), ("checkpoint", 7), ("end", 11)]),
+])
+def test_trainer_counts_overlapped_steps_and_drains(tracer, tmp_path,
+                                                    ckpt_every, drains):
+    """Every step but those after a drain is dispatched with another in
+    flight; a drain settles one step (checkpoint drains before steps 4 and
+    8, then the end) in its own spans, outside any ``train/step``."""
+    obs.metrics.reset()
+    kw = {} if ckpt_every is None else {"ckpt_dir": str(tmp_path / "ck"),
+                                        "ckpt_every": ckpt_every}
+    _tiny_trainer(total_steps=12, **kw).train(12)
+    snap = obs.metrics.snapshot()
+    assert snap["train_steps_overlapped_total"][0]["value"] == \
+        12 - len(drains)
+    by_reason = {r["labels"]["reason"]: r["value"]
+                 for r in snap["train_pipeline_drains_total"]}
+    assert by_reason == {r: sum(d[0] == r for d in drains) for r, _ in drains}
+    evs = tracer.events
+    drain_evs = [e for e in evs if e["name"] == "train/drain"]
+    assert [(e["args"]["reason"], e["args"]["step"]) for e in drain_evs] \
+        == drains
+
+    def within(e, d):
+        return d["ts"] <= e["ts"] and e["ts"] + e["dur"] <= d["ts"] + d["dur"]
+
+    for d in drain_evs:
+        assert sorted(e["name"] for e in evs if e is not d and within(e, d)) \
+            == ["train/accept", "train/block_until_ready", "train/readback"]
+        assert not any(within(d, e) for e in evs if e["name"] == "train/step")
+    obs.metrics.reset()
+
+
 def test_trainer_spans_reach_the_profiler_trace(tracer, tmp_path):
     """Enabled spans are also TraceMe annotations: each of the loop's spans
     sits on the profiler's host plane once per step, ``train/step`` with

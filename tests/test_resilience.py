@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import (
     MeshConfig,
     OptimizerConfig,
@@ -385,6 +386,185 @@ def test_divergence_guard_rolls_back_in_trainer(tmp_path):
     rep = tr.train(10)
     assert rep.rollbacks >= 1
     assert rep.steps == 10
+
+
+# -------------------------------------------------------------------------
+# trainer: one step in flight
+
+
+class _WatchedLoss:
+    """A step's loss that logs when the trainer waits on it and reads it."""
+
+    def __init__(self, x, i, log):
+        self.x, self.i, self.log = x, i, log
+
+    def block_until_ready(self):
+        self.log.append(("wait", self.i))
+        self.x.block_until_ready()
+        return self
+
+    def __float__(self):
+        self.log.append(("read", self.i))
+        return float(self.x)
+
+
+class _Recorder:
+    """Wraps a trainer's jitted step: logs each call's dispatch and the wait
+    on and read of its loss, keeps each call's batch and the newest state."""
+
+    def __init__(self, step_fn):
+        self.step_fn = step_fn
+        self.log = []
+        self.batches = []
+        self.state = None
+
+    def __call__(self, state, batch):
+        i = len(self.batches)
+        self.batches.append({k: np.asarray(v) for k, v in batch.items()})
+        self.log.append(("dispatch", i))
+        self.state, metrics = self.step_fn(state, batch)
+        return self.state, dict(metrics,
+                                loss=_WatchedLoss(metrics["loss"], i,
+                                                  self.log))
+
+
+def _recorded_trainer(tmp_path, **kw):
+    tr = _make_trainer(tmp_path, **kw)
+    rec = tr.step_fn = _Recorder(tr.step_fn)
+    return tr, rec
+
+
+def test_pipelined_train_matches_the_step_run_in_order_bitwise(tmp_path):
+    """Keeping a step in flight changes nothing the device computes: the
+    losses and final parameters equal those of the jitted step called in
+    order on the same batches, each waited on (across two checkpoint
+    drains and the end)."""
+    from repro.train.train_step import init_train_state
+
+    tr, rec = _recorded_trainer(tmp_path / "ck")
+    rep = tr.train(10)
+    state = init_train_state(tr.model, tr.run,
+                             jax.random.PRNGKey(tr.run.seed))
+    losses = []
+    for b in rec.batches:
+        state, metrics = rec.step_fn(
+            state, {k: jnp.asarray(v) for k, v in b.items()})
+        losses.append(float(metrics["loss"]))
+    assert len(losses) == 10 and rep.losses == losses
+    for got, want in zip(jax.tree.leaves(rec.state), jax.tree.leaves(state)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_next_step_is_dispatched_before_the_previous_loss_is_read(tmp_path):
+    """Step i+1 is queued before the host waits on step i, except where a
+    checkpoint (every 4 steps) needs step i settled first."""
+    tr, rec = _recorded_trainer(tmp_path / "ck")
+    tr.train(10)
+    at = {ev: k for k, ev in enumerate(rec.log)}
+    assert len(at) == len(rec.log) == 30       # each event once
+    for i in range(9):
+        if (i + 1) % 4:
+            assert at[("dispatch", i + 1)] < at[("wait", i)] \
+                < at[("read", i)], i
+        else:
+            assert at[("read", i)] < at[("dispatch", i + 1)], i
+    assert rec.log[-2:] == [("wait", 9), ("read", 9)]
+
+
+def test_guard_on_a_step_with_the_next_in_flight_rolls_back_and_replays(
+        tmp_path):
+    """Step 5's loss reads NaN while step 6 is in flight: step 6 is
+    discarded, step 5's batch (not 6's) takes the failure, the rollback to
+    step 4 replays batches 4, 5 and 6, and the EpochLog ends with the
+    fault-free run's SLs."""
+    ref = _make_trainer(tmp_path / "ref")
+    ref.train(10)
+
+    obs.metrics.reset()
+    faults.install(FaultPlan.parse("nan_loss@5"))
+    tr, rec = _recorded_trainer(tmp_path / "ck")
+    rep = tr.train(10)
+    assert rep.rollbacks == 1 and rep.guard_violations == 1
+    assert rec.log.index(("dispatch", 6)) < rec.log.index(("read", 5))
+    assert len(rec.batches) == 13
+    for replayed, first in zip(rec.batches[7:10], rec.batches[4:7]):
+        np.testing.assert_array_equal(replayed["tokens"], first["tokens"])
+    assert tr.skiplist.state()["failures"] == [[[0, 5], 1]]
+    assert list(tr.epoch_log.seq_lens()) == list(ref.epoch_log.seq_lens())
+    assert len(rep.losses) == 10 and all(np.isfinite(rep.losses))
+    drains = {r["labels"]["reason"]: r["value"] for r in
+              obs.metrics.snapshot()["train_pipeline_drains_total"]}
+    assert drains == {"checkpoint": 2, "guard": 1, "end": 1}
+    obs.metrics.reset()
+
+
+@pytest.mark.parametrize("spec, mesh_shape", [
+    ("nan_loss@5,preempt@6", (1,)),
+    ("nan_loss@5,peer_loss@5:host=2", (4,)),
+])
+def test_interruption_drain_that_trips_a_guard_rolls_back_first(
+        tmp_path, spec, mesh_shape):
+    """A preemption (or a confirmed peer loss) arrives at step 6 while step
+    5, in flight, reads NaN: the drain rolls back to step 4, and the
+    emergency checkpoint (or the re-mesh) then pins step 4, so the finished
+    run logs the fault-free run's SLs."""
+    ref = _make_trainer(tmp_path / "ref", mesh_shape=mesh_shape)
+    ref.train(10)
+    faults.install(FaultPlan.parse(spec))
+    ck = tmp_path / "ck"
+    tr = _make_trainer(ck, mesh_shape=mesh_shape)
+    rep = tr.train(10)
+    assert rep.rollbacks == 1
+    assert tr.skiplist.state()["failures"] == [[[0, 5], 1]]
+    preempted = "preempt" in spec
+    assert rep.preempted == preempted
+    if preempted:
+        assert rep.steps == 4
+        tr = _make_trainer(ck, mesh_shape=mesh_shape)
+        rep = tr.train(6)
+        assert rep.resumed_from == 4 and rep.rollbacks == 0
+    else:
+        assert rep.remeshes == 1 and rep.steps == 10
+    assert list(tr.epoch_log.seq_lens()) == list(ref.epoch_log.seq_lens())
+
+
+class _FeedEnded(Exception):
+    pass
+
+
+class _EndingFeed:
+    """A data iterator that raises after ``n`` batches, as a benchmark's
+    feed does when its window closes."""
+
+    def __init__(self, data, n):
+        self.data, self.n = data, n
+
+    def state(self):
+        return self.data.state()
+
+    def restore(self, state):
+        self.data.restore(state)
+
+    def __iter__(self):
+        inner = iter(self.data)
+        for _ in range(self.n):
+            yield next(inner)
+        raise _FeedEnded()
+
+
+def test_exception_leaving_train_accepts_the_step_in_flight(tmp_path):
+    obs.metrics.reset()
+    tr = _make_trainer(tmp_path / "ck", ckpt_every=100)
+    tr.data = _EndingFeed(tr.data, 5)
+    with pytest.raises(_FeedEnded):
+        tr.train(10)
+    assert tr.epoch_log.num_iterations == 5
+    snap = obs.metrics.snapshot()
+    assert [(r["labels"], r["value"]) for r in
+            snap["train_pipeline_drains_total"]] == \
+        [({"reason": "exception"}, 1)]
+    assert snap["train_steps_overlapped_total"][0]["value"] == 4
+    obs.metrics.reset()
 
 
 # -------------------------------------------------------------------------
