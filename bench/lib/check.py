@@ -18,11 +18,14 @@ through the same three batches from the same weights:
 A stacked-layer leaf counts once per layer. The reference is the family's
 plain forward at ``highest`` matmul precision, differentiated by JAX, with
 its own clipping, schedule and AdamW written out below from the numbers
-the configuration file states.
+the configuration file states. Where the configuration states
+``reference_row_blocks``, the reference takes each step's gradient over
+that many slices of the rows, so that it fits one chip beside its state.
 """
 from __future__ import annotations
 
 import importlib
+import math
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -57,27 +60,58 @@ def _padded(x: np.ndarray, width: Optional[int], fill: int) -> np.ndarray:
     return np.pad(x, ((0, 0), (0, width - x.shape[1])), constant_values=fill)
 
 
-def reference_steps(cfgspec: dict, params: Any, batches: List[Any], *,
-                    rows: Optional[slice] = None,
-                    pad_to: Optional[int] = None) -> Dict[str, Any]:
-    """Train ``params`` (donated) through ``batches`` with the reference.
+def row_blocks(cfgspec: dict, batch: int) -> int:
+    """The configuration's ``reference_row_blocks`` (default 1): how many
+    slices of each batch's rows the reference takes its gradient over, one
+    after another, so that it fits beside its own state. Raises, naming the
+    configuration, unless it is an integer >= 1 that divides ``batch``."""
+    n = cfgspec.get("reference_row_blocks", 1)
+    if isinstance(n, bool) or not isinstance(n, int) or n < 1 or batch % n:
+        raise ValueError(f"{cfgspec['name']}: reference_row_blocks={n!r} "
+                         f"must be an integer >= 1 that divides the batch "
+                         f"of {batch}")
+    return n
 
-    Returns the losses, the first step's clipped gradient norms per leaf
-    and the final parameters. ``rows`` keeps only some rows of each batch
-    (a planted fault).
-    ``pad_to`` pads every batch further, to one width, so that one
-    compiled reference serves every seed: the extra positions come after
-    each document and carry no label, so no loss or gradient changes."""
+
+def reference_step(cfgspec: dict, blocks: int = 1) -> Callable:
+    """The reference's jitted optimizer step, ``(params, m, v, tokens,
+    labels, t, lr) -> (params, m, v, loss, clipped gradient norms)``, with
+    params, m and v donated.
+
+    With ``blocks`` > 1 the loss and gradient are taken over that many
+    slices of the rows in turn, each slice's weighted by its share of the
+    batch's labelled positions, so that their sum is the whole batch's mean
+    loss and its gradient; clipping and the update then run once."""
     fam = family(cfgspec)
     opt = cfgspec["optimizer"]
     b1, b2, eps, wd = opt["beta1"], opt["beta2"], opt["eps"], \
         opt["weight_decay"]
+
     def loss_fn(p, tokens, labels):
         return fam.loss(p, tokens, labels, cfgspec["model"])
 
+    def loss_and_grad(p, tokens, labels):
+        if blocks == 1:
+            return jax.value_and_grad(loss_fn)(p, tokens, labels)
+        total = jnp.maximum(jnp.sum(labels >= 0), 1).astype(jnp.float32)
+
+        def block(acc, rows):
+            tok, lab = rows
+            share = jnp.sum(lab >= 0).astype(jnp.float32) / total
+            loss, g = jax.value_and_grad(loss_fn)(p, tok, lab)
+            return (acc[0] + share * loss,
+                    jax.tree.map(lambda a, x: a + share * x, acc[1], g)), None
+
+        zero = (jnp.zeros((), jnp.float32),
+                jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p))
+        sliced = jax.tree.map(
+            lambda x: x.reshape((blocks, -1) + x.shape[1:]), (tokens, labels))
+        (loss, g), _ = jax.lax.scan(block, zero, sliced)
+        return loss, jax.tree.map(lambda a, x: a.astype(x.dtype), g, p)
+
     def step(p, m, v, tokens, labels, t, lr):
         with jax.default_matmul_precision("highest"):
-            loss, g = jax.value_and_grad(loss_fn)(p, tokens, labels)
+            loss, g = loss_and_grad(p, tokens, labels)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x))
                              for x in jax.tree.leaves(g)))
         scale = jnp.minimum(1.0, opt["grad_clip"] / jnp.maximum(gnorm, 1e-9))
@@ -95,7 +129,27 @@ def reference_steps(cfgspec: dict, params: Any, batches: List[Any], *,
         p = jax.tree_util.tree_map_with_path(upd, p, m, v)
         return p, m, v, loss, leaf_norms(g)
 
-    jstep = jax.jit(step, donate_argnums=(0, 1, 2))
+    return jax.jit(step, donate_argnums=(0, 1, 2))
+
+
+def reference_steps(cfgspec: dict, params: Any, batches: List[Any], *,
+                    rows: Optional[slice] = None,
+                    pad_to: Optional[int] = None) -> Dict[str, Any]:
+    """Train ``params`` (donated) through ``batches`` with the reference.
+
+    Returns the losses, the first step's clipped gradient norms per leaf
+    and the final parameters. ``rows`` keeps only some rows of each batch
+    (a planted fault); the kept rows are then taken in gcd(blocks, rows
+    kept) slices, ``blocks`` being the configuration's
+    ``reference_row_blocks``: with half the rows kept, a slice holds no
+    more rows than a slice of the whole batch does.
+    ``pad_to`` pads every batch further, to one width, so that one
+    compiled reference serves every seed: the extra positions come after
+    each document and carry no label, so no loss or gradient changes."""
+    opt = cfgspec["optimizer"]
+    blocks = row_blocks(cfgspec, len(batches[0].tokens))
+    kept = len(batches[0].tokens[rows if rows is not None else slice(None)])
+    jstep = reference_step(cfgspec, math.gcd(blocks, kept))
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
     losses, g1 = [], None

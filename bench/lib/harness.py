@@ -223,6 +223,32 @@ def _model_config(cfgspec: dict):
     return base.with_overrides(**fields)
 
 
+REMAT = ("none", "block", "save_boundaries")    # what the program's Runtime takes
+
+
+def runtime_fields(cfgspec: dict) -> Dict[str, Any]:
+    """The ``RunConfig`` fields that the configuration's optional ``runtime``
+    object states: how the train step runs, not what it computes.
+
+    * ``remat``: one of ``REMAT``; ``"block"`` recomputes each layer period
+      in the backward pass and keeps only the layer boundaries.
+
+    A key left out keeps the program's default (``"none"``), so a file
+    without ``runtime`` builds the program's default ``RunConfig``.
+    ``train_mfu`` counts the model's FLOPs without recompute, so
+    a cell under ``remat`` reads its MFU against the model's work."""
+    rt = cfgspec.get("runtime", {})
+    name = cfgspec["name"]
+    unknown = sorted(set(rt) - {"remat"})
+    if unknown:
+        raise ValueError(f"{name}: unknown 'runtime' keys {unknown}; "
+                         "known: ['remat']")
+    if "remat" in rt and rt["remat"] not in REMAT:
+        raise ValueError(f"{name}: runtime.remat={rt['remat']!r}; the "
+                         f"program takes one of {list(REMAT)}")
+    return dict(rt)
+
+
 def build_trainer(cfgspec: dict, traffic: dict, feed: Feed):
     """The program's trainer on one device, as the configuration states."""
     from repro.configs import (
@@ -247,7 +273,8 @@ def build_trainer(cfgspec: dict, traffic: dict, feed: Feed):
             eps=o["eps"], weight_decay=o["weight_decay"],
             grad_clip=o["grad_clip"], warmup_steps=o["warmup_steps"]),
         param_dtype=cfgspec["dtypes"]["param"],
-        compute_dtype=cfgspec["dtypes"]["compute"])
+        compute_dtype=cfgspec["dtypes"]["compute"],
+        **runtime_fields(cfgspec))
     model = build_model(mcfg, Runtime.from_run(run))
     return Trainer(model, run, feed, total_steps=o["total_steps"])
 
@@ -282,6 +309,7 @@ class Program:
         from bench.lib.weights import leaf_norms, make_init_fn
         from repro.train.train_step import init_train_state
 
+        check.row_blocks(cfgspec, traffic["batch"])     # fail before set-up
         self.trainer = build_trainer(cfgspec, traffic, None)
         model, run = self.trainer.model, self.trainer.run
         shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))

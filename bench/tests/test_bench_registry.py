@@ -46,7 +46,8 @@ def test_readers_return_nothing_without_a_trace():
     w = Window(setup_s=1.0, window_s=2.0, fetches=[], chips=1, flops=len,
                peak=None, compiles=[])
     for name in ("device_idle_share", "device_step_p95_ms",
-                 "trainer_host_ms", "window_compiles", "train_mfu"):
+                 "trainer_host_ms", "window_compiles", "train_mfu",
+                 "train_tokens_per_busy_s"):
         assert load_reader(name)(w) is None
 
 
@@ -64,6 +65,11 @@ def _traced_window():
                   planes={"/device:TPU:0": {"XLA Ops": ops0},
                           "/device:TPU:1": {"XLA Ops": ops1},
                           "/host:CPU": {"main/1": [("x", 0.0, 1.0)]}})
+
+
+def test_tokens_per_busy_second_leave_the_idle_time_out():
+    # chip 0 is busy 6 s of the 10, chip 1 4 s: 16 tokens over 5 s
+    assert load_reader("train_tokens_per_busy_s")(_traced_window()) == 3.2
 
 
 def test_a_device_op_metric_is_added_by_a_file_alone(tmp_path):
